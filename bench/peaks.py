@@ -1,0 +1,26 @@
+"""Published peaks of each accelerator, keyed by JAX's ``device_kind``.
+
+A device that is not in the table is an error, never a default: a utilization
+against a guessed peak is worse than none.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e" (system architecture table):
+    # 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+
+
+def peak(device_kind: str) -> dict:
+    """The peaks of ``device_kind``; raises ``KeyError`` for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
