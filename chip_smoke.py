@@ -74,20 +74,6 @@ def check(cond: bool, what: str) -> None:
         raise SmokeFailure(what)
 
 
-class CompileClock:
-    """Seconds XLA spent compiling, read from JAX's compile events."""
-
-    def __init__(self):
-        import jax.monitoring
-        self.seconds = 0.0
-
-        def listen(event: str, duration: float, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.seconds += duration
-
-        jax.monitoring.register_event_duration_secs_listener(listen)
-
-
 def device_phase(chips: int) -> dict:
     import jax
     from repro.kernels import backend
@@ -143,7 +129,6 @@ def main_path_phase(*, batch: int = BATCH, seq_len: int = SEQ_LEN,
                     scan_steps: int = SCAN_STEPS, reduced: bool = False
                     ) -> None:
     import jax
-    clock = CompileClock()
     t0 = time.perf_counter()
     tr, cfg = build_trainer(batch=batch, seq_len=seq_len,
                             num_samples=num_samples, epochs=epochs,
@@ -159,20 +144,19 @@ def main_path_phase(*, batch: int = BATCH, seq_len: int = SEQ_LEN,
     # compiled in epoch 0 and counted there, and warmup's cloned carry
     # would hold a second copy of the train state next to the step.
     for epoch in range(epochs):
-        c0 = clock.seconds
-        t0 = time.perf_counter()
         (stats,) = tr.run(epochs=epoch + 1)[epoch:]
-        jax.block_until_ready(tr.params)
-        wall = time.perf_counter() - t0
-        compile_s = clock.seconds - c0
+        # The trainer's own record (repro/tracing.py): the epoch span's
+        # seconds and the compiles it counted.
+        compile_s = stats.counters.get("compile_s", 0.0)
         hidden = len(plans[epoch].hidden_indices)
         moved_back = len(plans[epoch].moveback_indices)
         steps = stats.bwd_samples // batch
         want_steps = math.ceil((num_samples - hidden) / batch)
         print(f"main: epoch={epoch} loss={stats.train_loss!r} "
               f"hidden={hidden} moved_back={moved_back} steps={steps} "
+              f"compiles={stats.counters.get('compiles', 0)} "
               f"compile_s={compile_s:.6f} "
-              f"s_per_step={(wall - compile_s) / max(steps, 1):.6f}")
+              f"s_per_step={(stats.wall_time - compile_s) / max(steps, 1):.6f}")
         check(math.isfinite(stats.train_loss),
               f"epoch {epoch}: loss {stats.train_loss}")
         check(steps == want_steps,

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
 from repro.core import planops
 from repro.core import selection as sel
 from repro.core.schedule import FractionSchedule, kakurenbo_lr
@@ -66,7 +67,7 @@ class KakurenboConfig:
 
 
 @functools.partial(
-    jax.jit,
+    tracing.jit, name=tracing.PLAN_STEP,
     static_argnames=("method", "tau", "drop_top", "moveback", "adjust_lr",
                      "mesh"))
 def _plan_step(state: SampleState, key: jax.Array, f_max: jax.Array, *,
@@ -141,23 +142,28 @@ class KakurenboSampler:
             alphas=c.fraction_alphas if c.reduce_fraction else (1.0,) * len(c.fraction_alphas),
             milestones=c.fraction_milestones,
         )
-        self._observe = jax.jit(scatter_observations)
+        self._observe = tracing.jit(scatter_observations,
+                                    tracing.OBSERVE_SCATTER)
 
     # -- epoch boundary ------------------------------------------------------
 
     def begin_epoch(self, epoch: int) -> EpochPlan:
         c = self.config
         f_max = float(self._fraction_schedule(epoch))
-        self._key, sub = jax.random.split(self._key)
-        hidden, moved_back, order, num_hidden, f_star, lr_scale = _plan_step(
-            self.state, sub, jnp.float32(f_max), method=c.selection,
-            tau=c.tau, drop_top=c.drop_top_fraction, moveback=c.moveback,
-            adjust_lr=c.adjust_lr, mesh=self.ctx.mesh)
-        self.state = with_hidden(self.state, hidden)
+        with tracing.span("plan.dispatch"):
+            self._key, sub = jax.random.split(self._key)
+            hidden, moved_back, order, num_hidden, f_star, lr_scale = (
+                _plan_step(self.state, sub, jnp.float32(f_max),
+                           method=c.selection, tau=c.tau,
+                           drop_top=c.drop_top_fraction,
+                           moveback=c.moveback, adjust_lr=c.adjust_lr,
+                           mesh=self.ctx.mesh))
+            self.state = with_hidden(self.state, hidden)
         # The single host sync of the epoch: materialise the plan (one
         # device_get for the order, the move-back mask and the scalars).
-        order_np, mb_np, nh, f_star, lr_scale = jax.device_get(
-            (order, moved_back, num_hidden, f_star, lr_scale))
+        with tracing.span("plan.fetch"):
+            order_np, mb_np, nh, f_star, lr_scale = jax.device_get(
+                (order, moved_back, num_hidden, f_star, lr_scale))
         self.host_round_trips += 1
         n = self.state.num_samples
         nh = int(nh)
@@ -209,9 +215,12 @@ class KakurenboSampler:
                 pad = np.full(batch_size - len(idx), idx[-1])
                 loss, pa, pc = eval_forward(np.concatenate([idx, pad]))
                 loss, pa, pc = loss[: len(idx)], pa[: len(idx)], pc[: len(idx)]
+                tracing.count("refresh_padded_rows", len(pad))
             else:
                 loss, pa, pc = eval_forward(idx)
-            self.observe(idx, loss, pa, pc, plan.epoch)
+            with tracing.span("refresh.observe"):
+                self.observe(idx, loss, pa, pc, plan.epoch)
+        tracing.count("refresh_rows", len(hidden))
         return int(len(hidden))
 
     def batches(self, plan: EpochPlan, batch_size: int) -> Iterator[np.ndarray]:

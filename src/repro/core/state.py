@@ -106,30 +106,31 @@ def scatter_observations(
     use.  The mesh trainer relies on this to keep the fused observe inside
     its jitted step without a second, shard-offset state contract.
     """
-    # A forgetting event (FORGET baseline) is a correct -> incorrect flip.
-    was_correct = state.prev_correct[indices]
-    epoch = jnp.asarray(epoch, jnp.int32)
-    if valid is None:
-        forget_inc = (was_correct & ~pa).astype(jnp.int32)
-        seen_val = jnp.broadcast_to(epoch, indices.shape)
-    else:
-        loss = jnp.where(valid, loss, state.loss[indices])
-        pa = jnp.where(valid, pa, state.pa[indices])
-        pc = jnp.where(valid, pc, state.pc[indices])
-        forget_inc = jnp.where(valid, was_correct & ~pa,
-                               False).astype(jnp.int32)
-        seen_val = jnp.where(valid, epoch, state.seen[indices])
-        pa_prev = jnp.where(valid, pa, state.prev_correct[indices])
-    return SampleState(
-        loss=state.loss.at[indices].set(loss.astype(jnp.float32)),
-        pa=state.pa.at[indices].set(pa),
-        pc=state.pc.at[indices].set(pc.astype(jnp.float32)),
-        hidden=state.hidden,
-        seen=state.seen.at[indices].set(seen_val),
-        forget_events=state.forget_events.at[indices].add(forget_inc),
-        prev_correct=state.prev_correct.at[indices].set(
-            pa if valid is None else pa_prev),
-    )
+    with jax.named_scope("observe"):
+        # A forgetting event (FORGET baseline) is a correct -> incorrect flip.
+        was_correct = state.prev_correct[indices]
+        epoch = jnp.asarray(epoch, jnp.int32)
+        if valid is None:
+            forget_inc = (was_correct & ~pa).astype(jnp.int32)
+            seen_val = jnp.broadcast_to(epoch, indices.shape)
+        else:
+            loss = jnp.where(valid, loss, state.loss[indices])
+            pa = jnp.where(valid, pa, state.pa[indices])
+            pc = jnp.where(valid, pc, state.pc[indices])
+            forget_inc = jnp.where(valid, was_correct & ~pa,
+                                   False).astype(jnp.int32)
+            seen_val = jnp.where(valid, epoch, state.seen[indices])
+            pa_prev = jnp.where(valid, pa, state.prev_correct[indices])
+        return SampleState(
+            loss=state.loss.at[indices].set(loss.astype(jnp.float32)),
+            pa=state.pa.at[indices].set(pa),
+            pc=state.pc.at[indices].set(pc.astype(jnp.float32)),
+            hidden=state.hidden,
+            seen=state.seen.at[indices].set(seen_val),
+            forget_events=state.forget_events.at[indices].add(forget_inc),
+            prev_correct=state.prev_correct.at[indices].set(
+                pa if valid is None else pa_prev),
+        )
 
 
 @jax.tree_util.register_dataclass

@@ -59,7 +59,8 @@ class Model:
         if cfg.family == "vlm" and logits.shape[1] != labels.shape[1]:
             logits = logits[:, -labels.shape[1]:]          # drop patch positions
             mask = mask[:, -labels.shape[1]:]
-        metrics = self._mod.per_sample_metrics(cfg, logits, labels, mask)
+        with jax.named_scope("scoring"):
+            metrics = self._mod.per_sample_metrics(cfg, logits, labels, mask)
         loss_vec, pa, pc = metrics
         w = batch.get("weight")
         weighted = loss_vec * w if w is not None else loss_vec

@@ -89,21 +89,26 @@ def _block(cfg: ArchConfig, ctx: ParallelCtx, p: dict, x: jax.Array,
            positions: jax.Array, is_global: jax.Array) -> tuple[jax.Array, jax.Array]:
     """One layer. Returns (x, aux_loss)."""
     aux = jnp.float32(0.0)
+    # Named scopes put "attention", "mlp" and "ssd_mixer" on the op names
+    # of the compiled step, for reading device time per layer in a trace.
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.family == "ssm":
-        x = x + ssm_mod.ssm_forward(p["ssm"], h, cfg.ssm, _d_inner(cfg),
-                                    cfg.norm_eps)
+        with jax.named_scope("ssd_mixer"):
+            x = x + ssm_mod.ssm_forward(p["ssm"], h, cfg.ssm, _d_inner(cfg),
+                                        cfg.norm_eps)
         return x, aux
-    q, k, v = attn.project_qkv(p["attn"], h, positions, cfg.rope_theta,
-                               cfg.qk_norm, cfg.norm_eps)
-    q = ctx.cs(q, "batch", None, "tp", None)
-    k = ctx.cs(k, "batch", None, "tp", None)
-    a = attn.attend(q, k, v, causal=True, window=cfg.attn_window,
-                    is_global=is_global)
-    a = jnp.einsum("bshk,hkd->bsd", a, p["attn"]["wo"].astype(x.dtype))
+    with jax.named_scope("attention"):
+        q, k, v = attn.project_qkv(p["attn"], h, positions, cfg.rope_theta,
+                                   cfg.qk_norm, cfg.norm_eps)
+        q = ctx.cs(q, "batch", None, "tp", None)
+        k = ctx.cs(k, "batch", None, "tp", None)
+        a = attn.attend(q, k, v, causal=True, window=cfg.attn_window,
+                        is_global=is_global)
+        a = jnp.einsum("bshk,hkd->bsd", a, p["attn"]["wo"].astype(x.dtype))
     if cfg.family == "hybrid":
-        s = ssm_mod.ssm_forward(p["ssm"], h, cfg.ssm, _d_inner(cfg),
-                                cfg.norm_eps)
+        with jax.named_scope("ssd_mixer"):
+            s = ssm_mod.ssm_forward(p["ssm"], h, cfg.ssm, _d_inner(cfg),
+                                    cfg.norm_eps)
         x = x + 0.5 * (a + s)  # hymba: mean-fused parallel heads
     else:
         x = x + a
@@ -112,8 +117,9 @@ def _block(cfg: ArchConfig, ctx: ParallelCtx, p: dict, x: jax.Array,
         y, aux = moe_mod.moe_ffn(p["moe"], h2, cfg.moe, ctx)
         x = x + y
     else:
-        x = x + gated_mlp(h2, p["mlp"]["w_gate"], p["mlp"]["w_up"],
-                          p["mlp"]["w_down"])
+        with jax.named_scope("mlp"):
+            x = x + gated_mlp(h2, p["mlp"]["w_gate"], p["mlp"]["w_up"],
+                              p["mlp"]["w_down"])
     x = ctx.cs(x, "batch", None, None)
     return x, aux
 

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import tracing
 from repro.core.state import TrainCarry
 from repro.core.strategy import SampleStrategy
 from repro.data.pipeline import epoch_index_plan
@@ -219,6 +220,7 @@ class ScanEpochEngine:
         self.tr = trainer
         self.scan_steps = max(int(trainer.cfg.scan_steps), 1)
         self._block = None   # built lazily: see _build_block
+        self._dispatched = 0  # train steps dispatched, for step annotations
 
     def _build_block(self):
         """Close the jitted scan block over the device-resident dataset.
@@ -261,7 +263,8 @@ class ScanEpochEngine:
                 return carry, jax.tree.map(lambda a: a[None], out)
             return jax.lax.scan(body, carry, xs, unroll=True)
 
-        self._block = jax.jit(block, donate_argnums=(0,))
+        self._block = tracing.jit(block, tracing.TRAIN_BLOCK,
+                                  donate_argnums=(0,))
 
     def warmup(self) -> int:
         """Compile every scan-block shape this engine can ever dispatch.
@@ -309,22 +312,24 @@ class ScanEpochEngine:
     def run_epoch(self, epoch: int, indices: np.ndarray, plan,
                   lr: float) -> EpochRunResult:
         tr, c = self.tr, self.tr.cfg
-        plan_idx = epoch_index_plan(np.asarray(indices), c.batch_size)
-        num_steps = plan_idx.shape[0]
-        if num_steps == 0:
-            return EpochRunResult(losses=np.zeros(0), fwd_samples=0,
-                                  bwd_samples=0, host_syncs=0)
-        if self._block is None:
-            self._build_block()
-        # Per-sample static weights are plan-time lookups (protocol
-        # contract), pre-gathered here in the host loop's exact call order.
-        w_rows = [tr.strategy.batch_weights(row) for row in plan_idx]
-        xs = {"idx": self._place_plan(plan_idx.astype(np.int32))}
-        if any(w is not None for w in w_rows):
-            # None rows mean uniform; weight 1.0 is exact (loss * 1.0).
-            xs["w"] = self._place_plan(np.stack(
-                [np.ones(c.batch_size, np.float32) if w is None
-                 else np.asarray(w, np.float32) for w in w_rows]))
+        with tracing.span("train.layout"):
+            plan_idx = epoch_index_plan(np.asarray(indices), c.batch_size)
+            num_steps = plan_idx.shape[0]
+            if num_steps == 0:
+                return EpochRunResult(losses=np.zeros(0), fwd_samples=0,
+                                      bwd_samples=0, host_syncs=0)
+            if self._block is None:
+                self._build_block()
+            # Per-sample static weights are plan-time lookups (protocol
+            # contract), pre-gathered here in the host loop's exact call
+            # order.
+            w_rows = [tr.strategy.batch_weights(row) for row in plan_idx]
+            xs = {"idx": self._place_plan(plan_idx.astype(np.int32))}
+            if any(w is not None for w in w_rows):
+                # None rows mean uniform; weight 1.0 is exact (loss * 1.0).
+                xs["w"] = self._place_plan(np.stack(
+                    [np.ones(c.batch_size, np.float32) if w is None
+                     else np.asarray(w, np.float32) for w in w_rows]))
         dev_state = (tr.strategy.get_device_state() if tr._thread_state
                      else None)
         carry = TrainCarry(tr.params, tr.opt_state, tr.ef_state, dev_state,
@@ -336,11 +341,15 @@ class ScanEpochEngine:
             for size in scan_block_sizes(num_steps, self.scan_steps):
                 xs_block = jax.tree.map(
                     lambda a: a[start : start + size], xs)
-                carry, (block_losses, block_bwds) = self._block(
-                    carry, xs_block, epoch_dev, lr)
+                # The step annotation numbers dispatches across epochs, so
+                # a profiler's step view keeps each block apart.
+                with tracing.span("train.dispatch", step=self._dispatched):
+                    carry, (block_losses, block_bwds) = self._block(
+                        carry, xs_block, epoch_dev, lr)
                 losses.append(block_losses)
                 bwds.append(block_bwds)
                 start += size
+                self._dispatched += size
         finally:
             # The scan block donates the whole carry: hand the latest live
             # buffers back even on a mid-epoch crash, so checkpoint-on-fault
@@ -357,7 +366,8 @@ class ScanEpochEngine:
         # The epoch's single loss/work materialisation: per-step scalars
         # (loss + the step's backward count) were accumulated on device
         # across the scan blocks; the guard counters ride the same fetch.
-        got_ls, got_bw, g = jax.device_get((losses, bwds, carry.gstate))
+        with tracing.span("train.fetch"):
+            got_ls, got_bw, g = jax.device_get((losses, bwds, carry.gstate))
         ls = np.concatenate([np.asarray(x, np.float64) for x in got_ls])
         bwd = int(np.sum(np.concatenate(
             [np.asarray(x, np.int64) for x in got_bw])))

@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import tracing
 from repro.checkpoint import checkpoint as ckpt
 from repro.core import (
     ForgetConfig, ISWRConfig, InfoBatchConfig, KakurenboConfig, LRSchedule,
@@ -179,6 +180,7 @@ class EpochStats:
     fwd_samples: int
     bwd_samples: int
     lr: float
+    # The ``epoch`` span's seconds (repro/tracing.py).
     wall_time: float
     # SampleState host round trips in the epoch's plan + batch loop (the
     # quantity the device-resident selection engine minimises; step-D
@@ -191,6 +193,12 @@ class EpochStats:
     # per-sample observations quarantined from the fused observe scatter.
     nonfinite_steps: int = 0
     quarantined_observations: int = 0
+    # The epoch's spans and counters (repro/tracing.py): phase intervals
+    # (name, parent, start, end, counts) on time.perf_counter, per-dispatch
+    # aggregates (parent, count, total_s, longest_s), and the counters
+    # (compiles, compile_s, refresh_rows, refresh_padded_rows).
+    spans: dict = dataclasses.field(default_factory=dict)
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 def _fused_scoring_loss_fn(logits_fn: Callable) -> Callable:
@@ -209,7 +217,9 @@ def _fused_scoring_loss_fn(logits_fn: Callable) -> Callable:
 
     def loss_fn(params, batch):
         logits = logits_fn(params, batch)
-        ce, pa, pc = kernel_ops.fused_loss_metrics(logits, batch["labels"])
+        with jax.named_scope("scoring"):
+            ce, pa, pc = kernel_ops.fused_loss_metrics(logits,
+                                                       batch["labels"])
         w = batch.get("weight")
         scalar = jnp.mean(ce * w) if w is not None else jnp.mean(ce)
         return scalar, (ce, pa, pc)
@@ -459,7 +469,7 @@ class Trainer:
 
         self._step_core = train_step
         self._train_step = jax.jit(train_step, donate_argnums=(0, 1, 2, 3, 4))
-        self._eval_step = jax.jit(eval_step)
+        self._eval_step = tracing.jit(eval_step, tracing.EVAL_FORWARD)
         self.engine = self._make_engine()
 
     def _make_engine(self):
@@ -737,8 +747,8 @@ class Trainer:
         # Forward-only metrics are per-sample (no cross-sample reductions in
         # the loss vector), so plain GSPMD over the sharded batch is already
         # bit-identical across mesh sizes; no chunking needed.
-        self._eval_step = jax.jit(
-            eval_step,
+        self._eval_step = tracing.jit(
+            eval_step, tracing.EVAL_FORWARD,
             in_shardings=(NamedSharding(mesh, P()),
                           NamedSharding(mesh, P("data"))))
 
@@ -754,9 +764,12 @@ class Trainer:
 
     def _epoch_indices(self, epoch: int):
         """Returns (visible indices, EpochPlan) for this epoch."""
-        self.strategy.prepare(
-            epoch, self._collect_feats if self.feats_fn is not None else None)
-        plan = self.strategy.plan(epoch)
+        with tracing.span("prepare"):
+            self.strategy.prepare(
+                epoch,
+                self._collect_feats if self.feats_fn is not None else None)
+        with tracing.span("plan"):
+            plan = self.strategy.plan(epoch)
         if plan.reinit_model:
             # e.g. FORGET: restart training from scratch on the pruned set.
             self.params = self._init_params(self.rng)
@@ -793,8 +806,16 @@ class Trainer:
         return np.concatenate([*per_worker, idx[n_used:]])
 
     def run_epoch(self, epoch: int) -> EpochStats:
+        with tracing.epoch(epoch) as rec:
+            stats = self._run_epoch(epoch, rec.start)
+        stats.wall_time = rec.seconds
+        stats.spans, stats.counters = rec.spans(), rec.counters
+        if logger.isEnabledFor(logging.INFO):
+            logger.info(tracing.summary(rec))
+        return stats
+
+    def _run_epoch(self, epoch: int, t0: float) -> EpochStats:
         c = self.cfg
-        t0 = time.perf_counter()
         indices, plan = self._epoch_indices(epoch)
         lr = float(c.lr(epoch)) * plan.lr_scale
         # The batch loop is the engine's job (train/engines.py): the host
@@ -802,7 +823,8 @@ class Trainer:
         # gathers batches on device and dispatches scan_steps-sized blocks.
         if self._straggler is not None:
             indices = self._rebalanced_order(indices)
-        res = self.engine.run_epoch(epoch, indices, plan, lr)
+        with tracing.span("train"):
+            res = self.engine.run_epoch(epoch, indices, plan, lr)
         if self._straggler is not None:
             # Feed the monitor this epoch's per-worker latencies.  Measured
             # wall time is uniform across simulated workers (one process),
@@ -836,30 +858,37 @@ class Trainer:
                     f"steps at epoch {epoch} (guard_abort_after="
                     f"{c.guard_abort_after}) — params are held at the last "
                     "finite update; restart from the latest checkpoint")
-        if plan.needs_refresh:
-            # KAKURENBO step D: forward-only refresh of the hidden list.
-            def fwd_fn(idx):
-                return self._eval_step(self.params, self.dataset.get(idx))
-            fwd += self.strategy.on_epoch_end(plan, fwd_fn, c.batch_size)
-        acc = self.evaluate() if (self.test_dataset is not None
-                                  and epoch % c.eval_every == 0) else float("nan")
-        stats = EpochStats(
-            epoch=epoch,
-            train_loss=(float(np.mean(res.losses)) if len(res.losses)
-                        else float("nan")),
-            test_acc=acc,
-            hidden_fraction=plan.hidden_fraction,
-            fwd_samples=fwd, bwd_samples=bwd, lr=lr,
-            wall_time=time.perf_counter() - t0,
-            host_syncs=plan.host_syncs + res.host_syncs,
-            engine=self.engine.name,
-            nonfinite_steps=nonfinite,
-            quarantined_observations=quarantined)
-        self.history.append(stats)
-        self.epoch = epoch + 1
-        if (c.checkpoint_dir and c.checkpoint_every
-                and (epoch + 1) % c.checkpoint_every == 0):
-            self.save_checkpoint()
+        with tracing.span("refresh"):
+            if plan.needs_refresh:
+                # KAKURENBO step D: forward-only refresh of the hidden list.
+                def fwd_fn(idx):
+                    with tracing.span("refresh.assemble"):
+                        batch = self.dataset.get(idx)
+                    with tracing.span("refresh.dispatch"):
+                        return self._eval_step(self.params, batch)
+                fwd += self.strategy.on_epoch_end(plan, fwd_fn, c.batch_size)
+        with tracing.span("eval"):
+            acc = (self.evaluate() if (self.test_dataset is not None
+                                       and epoch % c.eval_every == 0)
+                   else float("nan"))
+        with tracing.span("finish"):
+            stats = EpochStats(
+                epoch=epoch,
+                train_loss=(float(np.mean(res.losses)) if len(res.losses)
+                            else float("nan")),
+                test_acc=acc,
+                hidden_fraction=plan.hidden_fraction,
+                fwd_samples=fwd, bwd_samples=bwd, lr=lr,
+                wall_time=0.0,   # set from the epoch span once it closes
+                host_syncs=plan.host_syncs + res.host_syncs,
+                engine=self.engine.name,
+                nonfinite_steps=nonfinite,
+                quarantined_observations=quarantined)
+            self.history.append(stats)
+            self.epoch = epoch + 1
+            if (c.checkpoint_dir and c.checkpoint_every
+                    and (epoch + 1) % c.checkpoint_every == 0):
+                self.save_checkpoint()
         return stats
 
     def run(self, epochs: int | None = None,
