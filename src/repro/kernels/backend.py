@@ -21,7 +21,8 @@ fused (ce, pa, pc) scoring inside the train step should run the Pallas
 kernel only where it compiles ("kernel"); under the interpreter it would be
 orders of magnitude slower than XLA, so the hot path falls back to the
 fused one-pass jnp reference ("reference") — the interpreted kernel stays
-reachable explicitly, for the parity suites.
+reachable explicitly, for the parity suites.  ``attention_backend()`` makes
+the same decision for ``models.attention.attend``'s flash kernel.
 """
 from __future__ import annotations
 
@@ -60,6 +61,10 @@ def scoring_backend() -> str:
     """Hot-path dispatch for the fused scoring: "kernel" where the Pallas
     kernel compiles, "reference" (fused one-pass jnp) under the interpreter."""
     return "reference" if use_interpret() else "kernel"
+
+
+#: Dispatch for ``models.attention.attend``'s flash kernel: the same answer.
+attention_backend = scoring_backend
 
 
 def probe_cache_clear() -> None:

@@ -35,12 +35,13 @@ backend_name = backend.backend_name
 scoring_backend = backend.scoring_backend
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "blk_q", "blk_k", "interpret"))
-def flash_attention(q, k, v, causal: bool = True, blk_q: int = 128,
-                    blk_k: int = 128, interpret: bool | None = None):
-    return _fa.flash_attention(q, k, v, causal=causal, blk_q=blk_q,
-                               blk_k=blk_k, interpret=backend.resolve(interpret))
+@functools.partial(jax.jit, static_argnames=("causal", "interpret"))
+def flash_attention(q, k, v, causal: bool = True,
+                    interpret: bool | None = None):
+    """(B, S, H, D) flash attention; differentiable, blocking from the
+    shape (``kernels/flash_attention.py``)."""
+    return _fa.flash_attention(q, k, v, causal=causal,
+                               interpret=backend.resolve(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

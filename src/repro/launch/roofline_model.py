@@ -98,7 +98,8 @@ def kernel_hbm_bytes(kernel: str, **shape) -> int:
     ``results/BENCH_kernels.json``.
 
     Shapes (keyword-only, mirroring each kernel's bench record):
-      flash_attention: b, s, hq, hkv, d     (q + k + v read, o written; f32)
+      flash_attention: b, s, hq, hkv, d     (forward: q + k + v read, o and
+                                             the f32 lse written; f32)
       ssd_scan:        b, s, nh, p, n       (x/dt/b/c read, y + state written)
       loss_confidence: t, v                 (logits + labels read; 3 outs)
       fused_scoring:   t, v                 (same traffic as loss_confidence)
@@ -110,7 +111,7 @@ def kernel_hbm_bytes(kernel: str, **shape) -> int:
     """
     if kernel == "flash_attention":
         b, s, hq, hkv, d = (shape[k] for k in ("b", "s", "hq", "hkv", "d"))
-        return F32 * (b * s * hq * d * 2 + b * s * hkv * d * 2)
+        return F32 * (b * s * hq * d * 2 + b * s * hkv * d * 2 + b * s * hq)
     if kernel == "ssd_scan":
         b, s, nh, p, n = (shape[k] for k in ("b", "s", "nh", "p", "n"))
         return F32 * (b * s * nh * p * 2      # x read + y written

@@ -1,4 +1,5 @@
-"""GQA attention: training/prefill (chunked-q flash-style) and decode paths.
+"""GQA attention: training/prefill (the Pallas flash kernel where eligible,
+else chunked-q jnp) and decode paths.
 
 Layouts:
   x       (B, S, d_model)
@@ -12,6 +13,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+from repro.kernels import backend
+from repro.kernels.flash_attention import flash_attention
 from repro.models.common import ParamDef, rms_norm, rope
 
 NEG_INF = -1e30
@@ -51,15 +55,47 @@ def _grouped_scores(q5, k, scale):
                       preferred_element_type=jnp.float32) * scale
 
 
+def _outside_any_mesh(x: jax.Array) -> bool:
+    """False when ``x`` lives on a mesh (a GSPMD jit or a shard_map body),
+    whatever its size: a Mosaic kernel is not partitioned, and a (1,) mesh
+    keeps the jnp path so that it computes what a larger mesh does."""
+    return jax.typeof(x).sharding.mesh.empty
+
+
+def _kernel_eligible(q: jax.Array, window: int | None) -> bool:
+    """Whether ``attend`` takes the flash kernel for these inputs."""
+    s, dh = q.shape[1], q.shape[3]
+    return (window is None and s % 128 == 0 and dh in (64, 128)
+            and backend.attention_backend() == "kernel"
+            and _outside_any_mesh(q))
+
+
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, *,
            causal: bool = True, window: int | None = None,
            is_global: jax.Array | bool = True,
            q_chunk: int = 512) -> jax.Array:
-    """Full-sequence attention with chunked-q online evaluation.
+    """Full-sequence attention.
 
-    ``window``: sliding-window size; applied unless ``is_global`` (a traced
-    bool works — hybrid archs mix global and SWA layers inside one scan).
+    The differentiable flash kernel (``kernels/flash_attention.py``) where
+    ``_kernel_eligible``; otherwise chunked-q jnp.  ``window``: sliding-window
+    size; applied unless ``is_global`` (a traced bool works — hybrid archs mix
+    global and SWA layers inside one scan).  Each trace counts
+    ``attention.kernel`` or ``attention.jnp``.
     """
+    if _kernel_eligible(q, window):
+        tracing.count("attention.kernel")
+        return flash_attention(q, k, v, causal=causal)
+    tracing.count("attention.jnp")
+    return attend_chunked(q, k, v, causal=causal, window=window,
+                          is_global=is_global, q_chunk=q_chunk)
+
+
+def attend_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                   causal: bool = True, window: int | None = None,
+                   is_global: jax.Array | bool = True,
+                   q_chunk: int = 512) -> jax.Array:
+    """``attend``'s jnp path: chunked-q evaluation that materialises each
+    chunk's (Cq, S) scores."""
     b, s, hq, dh = q.shape
     hkv = k.shape[2]
     g = hq // hkv

@@ -51,6 +51,10 @@ PHASES = ("prepare", "plan", "train", "refresh", "eval", "finish")
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
+# Counted once per trace of ``models.attention.attend``, by the path taken;
+# they land in the epoch that compiles.
+ATTENTION_PATHS = ("attention.kernel", "attention.jnp")
+
 
 def jit(fun, name: str, **kwargs):
     """``jax.jit(fun, **kwargs)`` whose XLA module is ``name`` (one of the
@@ -189,10 +193,11 @@ def summary(rec: EpochRecord) -> str:
                        for k in ("refresh.assemble", "refresh.dispatch",
                                  "refresh.observe") if k in agg)
     c = rec.counters
+    paths = "".join(f"{k}={int(c[k])} " for k in ATTENTION_PATHS if k in c)
     return (f"epoch {rec.epoch}: {rec.seconds:.4f} s ({phases}) "
             f"longest_dispatch={longest:.4f} "
             f"compiles={int(c.get('compiles', 0))} "
-            f"compile_s={c.get('compile_s', 0.0):.3f} [{where}] "
+            f"compile_s={c.get('compile_s', 0.0):.3f} [{where}] {paths}"
             f"refresh_rows={int(c.get('refresh_rows', 0))} "
             f"padded_rows={int(c.get('refresh_padded_rows', 0))}"
             + (f" ({refresh})" if refresh else ""))

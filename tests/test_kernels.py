@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels import flash_attention as fa
 from repro.kernels.ref import (flash_attention_ref, histogram_ref,
                                loss_confidence_ref, minmax_ref)
 from repro.kernels.threshold_select import BIG, histogram_with_range
@@ -24,12 +24,96 @@ def test_flash_attention(b, s, hq, hkv, d, causal, dtype, rng):
     q = jnp.asarray(rng.normal(size=(b, s, hq, d)), dtype)
     k = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
     v = jnp.asarray(rng.normal(size=(b, s, hkv, d)), dtype)
-    out = flash_attention(q, k, v, causal=causal, blk_q=64, blk_k=64)
+    # f32 operands at the MXU for f32 inputs: the algorithm against the f32
+    # oracle, without the bf16 rounding of the kernel's operands.  The
+    # forward kernel runs where the call is differentiated (``jax.vjp``); an
+    # undifferentiated call at these one-tile shapes is XLA's attention.
+    out = jax.vjp(lambda *a: fa._attention(
+        *a, causal=causal, interpret=None,
+        f32_operands=dtype == jnp.float32), q, k, v)[0]
     ref = flash_attention_ref(q, k, v, causal=causal)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("s,hq,hkv", [(512, 9, 3), (640, 4, 4)])
+def test_flash_attention_forward_only_call(s, hq, hkv, rng):
+    """Undifferentiated: XLA's attention up to ``XLA_FORWARD_MAX_S``
+    positions (bit-identical to the oracle), the forward kernel past it (to
+    bf16 rounding)."""
+    b, d = 1, 64
+    q = jnp.asarray(rng.normal(size=(b, s, hq, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(b, s, hkv, d)), jnp.float32)
+            for _ in range(2))
+    out = jax.jit(fa.flash_attention)(q, k, v)
+    ref = flash_attention_ref(q, k, v)
+    if s <= fa.XLA_FORWARD_MAX_S:
+        np.testing.assert_array_equal(out, jax.jit(flash_attention_ref)(
+            q, k, v))
+    else:
+        assert _rel(out, ref) < BF16_OPERANDS_REL
+
+
+def test_flash_attention_rejects_head_dim_over_128():
+    q = jnp.zeros((1, 128, 2, 256), jnp.float32)
+    with pytest.raises(ValueError, match="D=256"):
+        fa.flash_attention(q, q, q)
+
+
+def _vjp_all(fn, q, k, v, ct):
+    """fn's output and its (dq, dk, dv) for cotangent ``ct``, one program."""
+    def both(q, k, v):
+        out, pull = jax.vjp(fn, q, k, v)
+        return (out,) + pull(ct)
+    return jax.jit(both)(q, k, v)
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+# Relative (Frobenius) error of bf16 MXU operands with f32 accumulation
+# against f32 arithmetic: each rounding is 2^-9 relative, and an output or
+# gradient sums a few of them (q k^T, P, P v; dO, dS in the backward).
+BF16_OPERANDS_REL = 1.5e-2
+
+
+# Every shape with the kernel's bf16 operands ("default"); the smollm
+# grouping, causal and not, also with f32 operands ("highest"), where the
+# algorithm must match to f32.  8 query heads on one kv head at S = 512
+# exceed one tile's rows: the (q tile, kv tile) grid of long sequences.
+VJP_CASES = [(s, hq, hkv, causal, "default", BF16_OPERANDS_REL)
+             for s in (128, 256) for hq, hkv in ((9, 3), (4, 4))
+             for causal in (True, False)] + [
+    (256, 9, 3, causal, "highest", 1e-5) for causal in (True, False)] + [
+    (512, 8, 1, causal, "highest", 1e-5) for causal in (True, False)]
+
+
+@pytest.mark.parametrize("s,hq,hkv,causal,precision,tol", VJP_CASES)
+def test_flash_attention_vjp_matches_oracles(s, hq, hkv, causal, precision,
+                                             tol, rng):
+    """The kernel's output and q, k, v gradients against ``jax.vjp`` of the
+    f32 oracle and of ``attend``'s jnp path: within bf16 rounding with bf16
+    operands, to f32 rounding with f32 operands."""
+    from repro.models.attention import attend_chunked
+    b, d = 2, 64
+    q = jnp.asarray(rng.normal(size=(b, s, hq, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(b, s, hkv, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(b, s, hkv, d)), jnp.float32)
+    ct = jnp.asarray(rng.normal(size=(b, s, hq, d)), jnp.float32)
+    oracle = _vjp_all(lambda *a: flash_attention_ref(*a, causal=causal),
+                      q, k, v, ct)
+    jnp_path = _vjp_all(lambda *a: attend_chunked(*a, causal=causal),
+                        q, k, v, ct)
+    got = _vjp_all(lambda *a: fa._attention(
+        *a, causal=causal, interpret=None,
+        f32_operands=precision == "highest"),
+        q, k, v, ct)
+    for name, g, o, j in zip(("o", "dq", "dk", "dv"), got, oracle, jnp_path):
+        assert _rel(g, o) < tol, (name, _rel(g, o))
+        assert _rel(g, j) < tol, (name, _rel(g, j))
 
 
 @pytest.mark.parametrize("b,s,nh,p,n,chunk", [

@@ -93,3 +93,23 @@ def test_flash_attention_compiles(one_chip):
     hlo = _hlo(lambda a, b, c: flash_attention.flash_attention(
         a, b, c, causal=True, interpret=False), q, kv, kv)
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("b,s", [(8, 512), (1, 2048)])
+def test_flash_attention_value_and_vjp_compile(one_chip, b, s):
+    """smollm's step shape (one grid step per group) and a longer sequence
+    (the causal tile grid): forward and both backward kernels."""
+    from repro.kernels import flash_attention
+    q = jax.ShapeDtypeStruct((b, s, 9, 64), jnp.float32, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, 3, 64), jnp.float32, sharding=one_chip)
+
+    def value_and_vjp(q, k, v):
+        out, pull = jax.vjp(lambda *a: flash_attention.flash_attention(
+            *a, causal=True, interpret=False), q, k, v)
+        return out, pull(out)
+
+    hlo = _hlo(value_and_vjp, q, kv, kv)
+    assert hlo.count("tpu_custom_call") >= 3
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert name in hlo
