@@ -2,6 +2,7 @@
 from.  Not part of a benchmark run.
 
     python3 bench/calibrate.py --workload <cell> --seeds 12 --first-seed <n>
+                               [--seed <m> ...]
 
 For each seed, in one process: the cell's set-up and one window epoch, then
 every number ``bench/check.py`` computes, read three ways —
@@ -18,7 +19,12 @@ every number ``bench/check.py`` computes, read three ways —
   mean taken over the rest, put in the program's place (a fault);
 - ``state_unchanged``: the reference with a learning rate of 0, put in the
   program's place: every step sees the initial parameters (a fault; it
-  reads 1 on ``update_norm_rel`` by construction).
+  reads 1 on ``update_norm_rel`` by construction);
+- ``answer_altered``, in KAKURENBO cells: the program's step-D losses
+  5% high (``refresh_rel``, ``refresh_mean_rel``; a fault);
+- ``exchange_left_out``, in a cell on several chips: the reference on the
+  rows of the first chip alone, put in the program's place (a fault: the
+  gradients are not exchanged, and each chip keeps its own).
 
 One JSON line per seed goes to standard output.
 """
@@ -33,8 +39,9 @@ import sys
 from run import ROOT, Cell, Run, checks, device_info, enable_compile_cache  # noqa: E402
 
 
-def half(batches):
-    return [(t[: len(t) // 2], l[: len(l) // 2]) for t, l in batches]
+def first_share(batches, parts: int):
+    """The first of ``parts`` equal shares of each batch's rows."""
+    return [(t[: len(t) // parts], l[: len(l) // parts]) for t, l in batches]
 
 
 def readings(cell: Cell, seed: int, arch_override=None) -> dict:
@@ -50,7 +57,9 @@ def readings(cell: Cell, seed: int, arch_override=None) -> dict:
     program, extra = checks(r, None)
     tap, ref = extra["tap"], extra["ref"]
     out = {"seed": seed, "program": program, "control": {}, "half_batch": {},
-           "state_unchanged": {}, "setup_hidden": r.setup_hidden,
+           "state_unchanged": {}, "exchange_left_out": {},
+           "answer_altered": {},
+           "setup_hidden": r.setup_hidden,
            "hidden": [len(p.hidden_indices) for p in r.plans]}
     batches = r.tap_batches(tap)
     if cell.kakurenbo:
@@ -70,21 +79,43 @@ def readings(cell: Cell, seed: int, arch_override=None) -> dict:
         del params
         f_loss, f_pc = extra["signals"]
         b = extra["train"]
-        for name, sl in (("observe_rel", slice(None, b)),
-                         ("refresh_rel", slice(b, None))):
-            out["control"][name] = max(check.sample_gap(loss[sl], f_loss[sl]),
-                                       check.sample_gap(pc[sl], f_pc[sl]))
+        out["refresh_rows"] = {
+            "reference": {"loss": f_loss[b:].tolist(),
+                          "pc": f_pc[b:].tolist()},
+            "program": extra["refreshed"],
+            "control": {"loss": np.asarray(loss[b:]).tolist(),
+                        "pc": np.asarray(pc[b:]).tolist()}}
+        for name, sl, reduce in (
+                ("observe_rel", slice(None, b), np.max),
+                ("refresh_rel", slice(b, None), np.max),
+                ("refresh_mean_rel", slice(b, None), np.mean)):
+            out["control"][name] = max(
+                check.sample_gap(loss[sl], f_loss[sl], reduce),
+                check.sample_gap(pc[sl], f_pc[sl], reduce))
+        # An answer altered where it is produced: step D's losses (the
+        # program's, read above) 5% high, as bench/tests plants it.
+        got = extra["refreshed"]
+        for name, reduce in (("refresh_rel", np.max),
+                             ("refresh_mean_rel", np.mean)):
+            out["answer_altered"][name] = max(
+                check.sample_gap(1.05 * np.asarray(got["loss"]), f_loss[b:],
+                                 reduce),
+                check.sample_gap(got["pc"], f_pc[b:], reduce))
     gc.collect()
     leaves = {"names": [jax.tree_util.keystr(p) for p, _ in
                         jax.tree_util.tree_leaves_with_path(ref["grad_tree"])],
               "program": check.grad_diffs(tap, ref).tolist()}
     out["grad_diff_leaves"] = leaves
-    for name, steps in (
-            ("control", dict(batches=batches, dtype=jnp.bfloat16)),
-            ("half_batch", dict(batches=half(batches),
-                                rows=max(r.reference_rows // 2, 1))),
-            ("state_unchanged", dict(batches=batches,
-                                     lrs=[0.0] * len(batches)))):
+    faults = [
+        ("control", dict(batches=batches, dtype=jnp.bfloat16)),
+        ("half_batch", dict(batches=first_share(batches, 2),
+                            rows=max(r.reference_rows // 2, 1))),
+        ("state_unchanged", dict(batches=batches, lrs=[0.0] * len(batches)))]
+    if cell.chips > 1:
+        faults.append(("exchange_left_out", dict(
+            batches=first_share(batches, cell.chips),
+            rows=min(r.reference_rows, r.batch // cell.chips))))
+    for name, steps in faults:
         kw = dict(batches=batches, lrs=tap["lrs"], rows=r.reference_rows)
         kw.update(steps)
         got = check.reference_steps(r.forward, r.initial_weights,
@@ -101,11 +132,14 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, default=12)
     ap.add_argument("--first-seed", type=int, default=1)
+    ap.add_argument("--seed", type=int, action="append", default=[],
+                    help="a seed to read before the range (repeatable)")
     args = ap.parse_args(argv)
     cell = Cell(args.workload)
     enable_compile_cache()
     device_info(cell.chips)
-    for seed in range(args.first_seed, args.first_seed + args.seeds):
+    for seed in [*args.seed,
+                 *range(args.first_seed, args.first_seed + args.seeds)]:
         out = readings(cell, seed)
         print(json.dumps(out, default=lambda x: None if x is None else float(x)
                          if not math.isinf(x) else "inf"), flush=True)

@@ -51,7 +51,10 @@ KAKURENBO cells add four layer checks:
   sample state for those rows (all of the batch; a sample of the hidden
   list) are compared with the reference forward pass at the same
   parameters; a row whose last observation is not that epoch reads
-  infinite.
+  infinite.  ``refresh_mean_rel`` is ``refresh_rel`` with the rows' gaps
+  averaged instead of their widest taken: the widest of a few rows swings
+  with the seed's weights far more than an answer altered moves it
+  (PERF.md), so a configuration may compare the mean.
 
 The no-hiding baseline's plan is checked to cover every sample once.
 """
@@ -225,11 +228,12 @@ def grad_diffs(tap: dict, ref: dict) -> np.ndarray:
     return diff / np.maximum(ref["grad"], np.median(ref["grad"]))
 
 
-def sample_gap(x, ref) -> float:
-    """Worst gap of per-sample values, each over the larger of its reference
-    value and the median one (a loss near 0 reads no relative noise)."""
+def sample_gap(x, ref, reduce=np.max) -> float:
+    """Worst (or, with ``reduce=np.mean``, mean) gap of per-sample values,
+    each over the larger of its reference value and the median one (a loss
+    near 0 reads no relative noise)."""
     x, ref = np.asarray(x, np.float64), np.abs(np.asarray(ref, np.float64))
-    return float(np.max(np.abs(x - ref) / np.maximum(ref, np.median(ref))))
+    return float(reduce(np.abs(x - ref) / np.maximum(ref, np.median(ref))))
 
 
 # ---------------------------------------------------------------------------
@@ -309,7 +313,8 @@ def reference_signals(forward, weights, tokens, labels, rows: int):
             np.concatenate([o[1] for o in out]))
 
 
-STRATEGY_NUMBERS = ("seen_diff", "observe_rel", "refresh_rel")
+STRATEGY_NUMBERS = ("seen_diff", "observe_rel", "refresh_rel",
+                    "refresh_mean_rel")
 
 
 def seen_diff(plans, states_after) -> int:
@@ -323,24 +328,25 @@ def seen_diff(plans, states_after) -> int:
     return diff
 
 
-def signals_gap(state: dict, rows: np.ndarray, epoch: int, loss, pc) -> float:
+def signals_gap(state: dict, rows: np.ndarray, epoch: int, loss, pc,
+                reduce=np.max) -> float:
     """The (loss, PC) recorded in ``state`` for ``rows`` against the
-    reference's ``loss`` and ``pc``; infinite when a row's last observation
-    is not ``epoch``."""
+    reference's ``loss`` and ``pc``, the larger of the two gaps; infinite
+    when a row's last observation is not ``epoch``."""
     if np.any(np.asarray(state["seen"])[rows] != epoch):
         return math.inf
-    return max(sample_gap(np.asarray(state["loss"])[rows], loss),
-               sample_gap(np.asarray(state["pc"])[rows], pc))
+    return max(sample_gap(np.asarray(state["loss"])[rows], loss, reduce),
+               sample_gap(np.asarray(state["pc"])[rows], pc, reduce))
 
 
 def refresh_gap(state: dict, hidden: np.ndarray, sample: np.ndarray,
-                epoch: int, loss, pc) -> float:
+                epoch: int, loss, pc, reduce=np.max) -> float:
     """Step D's (loss, PC) for ``sample`` of the ``hidden`` list against the
     reference's; infinite when the list is empty or a row of it was not
     refreshed at ``epoch``."""
     if not len(hidden) or np.any(np.asarray(state["seen"])[hidden] != epoch):
         return math.inf
-    return signals_gap(state, sample, epoch, loss, pc)
+    return signals_gap(state, sample, epoch, loss, pc, reduce)
 
 
 @jax.jit

@@ -10,7 +10,9 @@ reference ``<name>.py`` beside it) and a traffic mix
    kernels), and names the platform, device kind and count;
 2. set-up: makes the weights on the device from the seed, the corpus from
    the seed, builds the program's model and ``Trainer`` (scan engine, one
-   step per block, AdamW, float32 parameters), and runs the mix's set-up
+   step per block, AdamW, float32 parameters; on a ``(chips,)`` data mesh
+   where the cell asks for several chips, with the global batch and the
+   gradient reduction the mix gives), and runs the mix's set-up
    epochs through the trainer's own ``run_epoch`` — epoch 0 with the step
    tap of ``bench/check.py`` — then compiles the remaining shapes the
    window can reach;
@@ -41,6 +43,7 @@ import shutil
 import sys
 import tempfile
 import time
+import typing
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
@@ -172,6 +175,25 @@ class CompileCounter:
 # ---------------------------------------------------------------------------
 
 
+def build_config(cls, kw: dict):
+    """``cls(**kw)`` from JSON-like values: a field whose type is a
+    dataclass (``ArchConfig.ssm``, ``.moe``, or any added later) is built
+    from its dict, found from ``cls``'s own field types, and lists become
+    tuples, so that frozen configurations stay hashable."""
+    hints = typing.get_type_hints(cls)
+    return cls(**{k: _field_value(hints[k], v) for k, v in kw.items()})
+
+
+def _field_value(hint, v):
+    if isinstance(v, dict):
+        for t in typing.get_args(hint) or (hint,):
+            if dataclasses.is_dataclass(t):
+                return build_config(t, v)
+    if isinstance(v, list):
+        return tuple(_field_value(None, x) for x in v)
+    return v
+
+
 def seed_key(seed: int, stream: int):
     """A threefry key from any non-negative seed (wider than 32 bits too)."""
     import jax
@@ -186,7 +208,7 @@ class Run:
 
     def __init__(self, cell: Cell, seed: int, arch_override=None):
         import jax
-        from repro.configs.base import ArchConfig, SSMConfig
+        from repro.configs.base import ArchConfig
         from repro.core import KakurenboConfig, LRSchedule
         from repro.models import build_model
         from repro.train import Trainer, TrainConfig
@@ -200,11 +222,16 @@ class Run:
         if arch_override is not None:
             cfg = arch_override(cfg)
         self.cfg, run = cfg, cfg["run"]
-        kw = cell.model_ref.program_arch(cfg)
-        if kw.get("ssm") is not None:
-            kw["ssm"] = SSMConfig(**kw["ssm"])
-        self.arch = ArchConfig(**kw)
-        self.batch, self.seq_len = run["batch_size"], run["seq_len"]
+        self.arch = build_config(ArchConfig, cell.model_ref.program_arch(cfg))
+        # A mix may set the global batch, and for a cell on several chips
+        # how its gradients are reduced over the ("data",) mesh.
+        self.batch = mix.get("batch_size", run["batch_size"])
+        self.seq_len = run["seq_len"]
+        mesh = {}
+        if cell.chips > 1:
+            mesh = {k: mix[k] for k in ("grad_chunks", "grad_allreduce")
+                    if k in mix}
+            mesh["mesh_shape"] = (cell.chips,)
         self.n = mix["batches_per_epoch"] * self.batch
         corpus = mix["corpus"]
         self.corpus = SyntheticLM(
@@ -232,11 +259,9 @@ class Run:
             optimizer_hp=dict(self.hp),
             lr=LRSchedule(run["base_lr"], sched["kind"],
                           sched["total_epochs"], sched["warmup_epochs"]),
-            kakurenbo=KakurenboConfig(**{
-                k: tuple(v) if isinstance(v, list) else v
-                for k, v in kak.items()}),
+            kakurenbo=build_config(KakurenboConfig, kak),
             engine=run["engine"], scan_steps=run["scan_steps"],
-            seed=seed % (2**31 - 1))
+            seed=seed % (2**31 - 1), **mesh)
         self.tr = Trainer(tc, lambda _key: weights, model.loss_and_metrics,
                           self.corpus, None)
         del weights
@@ -304,7 +329,6 @@ class Run:
         of the sample state before each plan: the engine's per-block slice
         of an epoch plan of each possible length, and the refresh's padded
         tail (its slice and its observe) of each possible remainder."""
-        import jax.numpy as jnp
         import numpy as np
         from bench.check import copy_state
         if not self.cell.kakurenbo:
@@ -313,15 +337,18 @@ class Run:
         mix, b, n = self.cell.traffic["kakurenbo"], self.batch, self.n
         most_hidden = int(np.floor(np.float32(mix["max_fraction"])
                                    * np.float32(n)))
+        place = self.tr.engine._place_plan
         for steps in range(-(-(n - most_hidden) // b), n // b + 1):
-            jnp.zeros((steps, b), jnp.int32)[0:1]
+            place(np.zeros((steps, b), np.int32))[0:1]
+        # The refresh's tail slices step D's forward outputs, which are
+        # row-sharded on a mesh: slice real ones, so each slice compiles for
+        # the layout the window gives it.
         strategy = self.tr.strategy
         saved = strategy.get_device_state()
+        signals = self.tr._eval_step(self.tr.params,
+                                     self.tr.dataset.get(np.arange(b)))
         for k in range(1, b):
-            idx = np.arange(k)
-            loss = jnp.zeros((b,), jnp.float32)[:k]
-            pa = jnp.zeros((b,), bool)[:k]
-            strategy.observe(idx, loss, pa, loss, 1)
+            strategy.observe(np.arange(k), *(x[:k] for x in signals), 1)
         strategy.set_device_state(saved)
 
     def tap_batches(self, tap: dict) -> list:
@@ -463,7 +490,14 @@ def checks(r: Run, names) -> tuple[dict, dict]:
                 state, rows["train"], epoch, loss[:b], pc[:b])
         numbers["refresh_rel"] = check.refresh_gap(
             state, rows["hidden"], rows["sample"], epoch, loss[b:], pc[b:])
-        extra.update(rows=both, train=b, signals=(loss, pc))
+        numbers["refresh_mean_rel"] = check.refresh_gap(
+            state, rows["hidden"], rows["sample"], epoch, loss[b:], pc[b:],
+            np.mean)
+        got = {k: np.asarray(state[k])[rows["sample"]].tolist()
+               for k in ("loss", "pc")}
+        log(f"refresh rows: program={got} reference="
+            f"{dict(loss=loss[b:].tolist(), pc=pc[b:].tolist())}")
+        extra.update(rows=both, train=b, signals=(loss, pc), refreshed=got)
     r.tr = None
     gc.collect()
     if names is not None:
@@ -495,6 +529,8 @@ class Record:
 
     def __init__(self, r: Run, window_s: float, trace, peak: dict):
         self.arch, self.seq_len, self.batch = r.arch, r.seq_len, r.batch
+        self.cfg, self.model_ref = r.cfg, r.cell.model_ref
+        self.chips = r.cell.chips
         self.epochs, self.spans = r.epochs, r.spans
         self.window = (r.t0, r.t1)
         self.window_s = window_s
@@ -530,6 +566,19 @@ def traced(r: Run, seconds: float):
         return window_s, tr_mod.load(tr_mod.find_xplane(log_dir))
     finally:
         shutil.rmtree(log_dir, ignore_errors=True)
+
+
+def memory_peak_bytes(devices) -> int:
+    """The peak of the fullest of ``devices``.  The TPU runtime keeps the
+    programs' temporaries in a reserved region that ``peak_bytes_in_use``
+    leaves out, so a chip's peak is both together."""
+    peaks = []
+    for d in devices:
+        mem = d.memory_stats() or {}
+        log(f"memory_stats {d.id}: {mem}")
+        peaks.append(int(mem.get("peak_bytes_in_use", 0)
+                         + mem.get("peak_bytes_reserved", 0)))
+    return max(peaks)
 
 
 def main(argv=None, *, require_tpu: bool = True, arch_override=None) -> int:
@@ -578,12 +627,7 @@ def main(argv=None, *, require_tpu: bool = True, arch_override=None) -> int:
         window_s, trace = r.window(args.seconds), None
     in_window = counter.compiles - before
     stats = r.epochs
-    mem = jax.devices()[0].memory_stats() or {}
-    log(f"memory_stats: {mem}")
-    # The TPU runtime keeps the programs' temporaries in a reserved region
-    # that peak_bytes_in_use leaves out; the chip's peak is both together.
-    peak_bytes = int(mem.get("peak_bytes_in_use", 0)
-                     + mem.get("peak_bytes_reserved", 0))
+    peak_bytes = memory_peak_bytes(jax.devices()[:cell.chips])
     device["memory_peak_bytes"] = peak_bytes
     hidden = [len(p.hidden_indices) for p in r.plans]
     log(f"window: window_s={window_s!r} epochs={len(stats)} "

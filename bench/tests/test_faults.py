@@ -6,7 +6,9 @@ skipped): a step that returns its state unchanged, half of the batch left
 out with the mean taken over the rest, and an answer (the per-sample and
 batch loss) altered where it is produced; in KAKURENBO cells also the
 fused observe writing to the wrong rows and a step D that refreshes
-nothing.  One chip has no exchange between chips to leave out.
+nothing.  A cell on several chips runs on as many virtual CPU devices in a
+process of its own (``bench/tests/cpu_run.py``), with one fault more: the
+exchange of gradients between the chips left out.
 """
 import json
 
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from bench import run
+from bench.tests.cpu_run import subprocess_result
 from bench.tests.tiny import tiny
 
 
@@ -65,6 +68,18 @@ def refresh_skipped(monkeypatch):
                         lambda self, plan, eval_forward, batch_size: 0)
 
 
+def exchange_left_out(monkeypatch):
+    """Each chip folds only its own chunks: the all-gather of the fold
+    hands every chip its own partials in each chip's place."""
+    import jax
+
+    def own_only(x, axis_name, **_):
+        n = jax.lax.axis_size(axis_name)
+        return jax.tree.map(
+            lambda a: jnp.broadcast_to(a[None], (n,) + a.shape), x)
+    monkeypatch.setattr(jax.lax, "all_gather", own_only)
+
+
 def not_correct(capsys, workload):
     rc = run.main(["--workload", workload, "--seed", "11", "--seconds", "0",
                    "--trace", "0"], require_tpu=False, arch_override=tiny)
@@ -89,3 +104,16 @@ def test_fault_is_not_correct(monkeypatch, capsys, fault, workload):
 def test_strategy_fault_is_not_correct(monkeypatch, capsys, fault, workload):
     fault(monkeypatch)
     not_correct(capsys, workload)
+
+
+
+@pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
+                                   "answer_altered", "exchange_left_out",
+                                   "observed_elsewhere", "refresh_skipped"])
+def test_mesh_fault_is_not_correct(fault):
+    res, _ = subprocess_result(
+        ["--devices", "4", "--fault", f"test_faults.{fault}", "--",
+         "--workload", "smollm-135m.kakurenbo.dp4", "--seed", "11",
+         "--seconds", "0", "--trace", "0"])
+    assert res["device"]["count"] == 4
+    assert res["correct"] is False, res["checks"]

@@ -14,6 +14,7 @@ import sys
 import pytest
 
 from bench import run
+from bench.tests.cpu_run import subprocess_result
 from bench.tests.tiny import tiny
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -78,3 +79,61 @@ def test_result_line(capsys, workload, trace):
     assert "compiles_in_window=0" in err
     tail = err.strip().splitlines()[-len(res["checks"]):]
     assert all(line.startswith("check ") for line in tail)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_four_chip_cell_on_four_cpu_devices(trace):
+    res, err = subprocess_result(
+        ["--devices", "4", "--peak", "1e12", "--", "--workload",
+         "smollm-135m.kakurenbo.dp4", "--seed", str(2**33 + 3),
+         "--seconds", "1", "--trace", str(trace)])
+    assert res["correct"] is True and res["failed"] == 0, res["checks"]
+    assert res["checks"]["plan_diff"]["value"] == 0
+    assert res["checks"]["seen_diff"]["value"] == 0
+    assert res["device"]["count"] == 4
+    assert "compiles_in_window=0" in err
+    assert "batch=16x32" in err and "samples=512" in err
+    # Every chip's memory was read for the peak.
+    assert all(f"memory_stats {i}:" in err for i in range(4))
+    cell = run.Cell("smollm-135m.kakurenbo.dp4")
+    want = cell.per_layer if trace else cell.end_to_end
+    if trace:
+        assert {"mfu", "refresh_ms", "plan_fetch_ms"} <= set(res["metrics"])
+        assert set(res["metrics"]) <= {m["name"] for m in want}
+    else:
+        assert set(res["metrics"]) == {m["name"] for m in want}
+
+
+def test_a_configuration_of_a_new_family_from_new_files_alone(tmp_path):
+    """A configuration that ``bench/flops.py`` cannot count (a mixture of
+    experts) runs through the harness of a copy of the benchmark to which
+    only its own files and entries were added, and ``mfu`` reads the count
+    its module gives."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    data = ROOT / "bench" / "tests" / "data"
+    for name in ("tiny-moe.json", "tiny-moe.py", "tiny-moe.limits.json"):
+        shutil.copy(data / name, tmp_path / "bench" / "configs")
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append({
+        "name": "tiny-moe", "source": "a test fixture",
+        "file": "bench/configs/tiny-moe.json", "reduced": [],
+        "why": "a family the harness's own files do not know"})
+    bench["workloads"].append({
+        "name": "tiny-moe.kakurenbo", "config": "tiny-moe",
+        "traffic": "kakurenbo", "chips": 1, "why": "the fixture's cell"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"))
+    res, err = subprocess_result(
+        ["--peak", "1e12", "--", "--workload", "tiny-moe.kakurenbo",
+         "--seed", str(2**32 + 9), "--seconds", "1", "--trace", "1"],
+        cwd=tmp_path, env=env)
+    assert res["correct"] is True, res["checks"]
+    (line,) = [ln for ln in err.splitlines()
+               if ln.startswith("window_flops ")]
+    got = json.loads(line.split(" ", 1)[1])
+    assert got["trained"] > 0 and got["chips"] == 1
+    assert res["metrics"]["mfu"]["value"] == pytest.approx(
+        100.0 * (got["trained"] + got["refreshed"])
+        / (got["window_s"] * got["chips"] * 1e12), rel=1e-12)
